@@ -108,7 +108,8 @@ def bench_weak_scaling(quick: bool):
             _row(
                 f"fig4_weak_{tag}_P{grid[0] * grid[1]}_N{n}",
                 r["wall_s"] * 1e6,
-                f"gflops={r['gflops']:.1f};coll_B/dev={r['coll_bytes_per_device']:.3g}",
+                f"gflops={r['gflops']:.1f};coll_B/dev={r['coll_bytes_per_device']:.3g};"
+                f"platform={r['platform']}",
             )
             _row(
                 f"fig5_weak_wall_{tag}_P{grid[0] * grid[1]}_N{n}",
@@ -133,7 +134,8 @@ def bench_strong_scaling(quick: bool):
             _row(
                 f"fig6_strong_{tag}_P{p}_N{n}",
                 r["wall_s"] * 1e6,
-                f"gflops={r['gflops']:.1f};flops/dev={r['flops_per_device_hlo']:.3g}",
+                f"gflops={r['gflops']:.1f};flops/dev={r['flops_per_device_hlo']:.3g};"
+                f"platform={r['platform']}",
             )
             _row(
                 f"fig7_strong_wall_{tag}_P{p}_N{n}",
@@ -164,7 +166,8 @@ def bench_strategies():
             r["wall_s"] * 1e6,
             f"coll_B/dev={r['coll_bytes_per_device']:.4g};"
             f"ag={r['coll_breakdown']['all-gather']:.3g};"
-            f"ar={r['coll_breakdown']['all-reduce']:.3g}",
+            f"ar={r['coll_breakdown']['all-reduce']:.3g};"
+            f"platform={r['platform']}",
         )
 
 
@@ -1288,6 +1291,9 @@ def main() -> None:
         "e.g. --only summa,contract (CI artifact jobs)",
     )
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     runners = {
         "summa": lambda: bench_planned_sparse(args.json),
         "sched": lambda: bench_sched(args.sched_json),

@@ -1,7 +1,9 @@
 """Shared runner for the paper's scaling experiments (Figs 4-8).
 
-Each measurement runs in a fresh subprocess with an emulated device count
-so the parent process keeps seeing one device.  Two metric classes:
+Each measurement runs in a fresh subprocess on the CPU backend
+(``JAX_PLATFORMS=cpu``) with an emulated device count, so the parent
+keeps seeing its own devices and never shares a chip with a child; every
+record carries the child's ``platform``.  Two metric classes:
 
 * wall-time / GFLOP-rate — what the paper plots.  CAVEAT (recorded in
   EXPERIMENTS.md): this container has ONE physical core, so emulated
@@ -65,6 +67,7 @@ wall = (time.perf_counter() - t0) / repeats
 
 flops_total = 2.0 * N * N * N
 print(json.dumps({
+    "platform": jax.devices()[0].platform,
     "wall_s": wall,
     "gflops": flops_total / wall / 1e9,
     "flops_per_device_hlo": wc.flops,
@@ -86,6 +89,7 @@ def run_config(
 ) -> dict:
     devices = grid[0] * grid[1]
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # emulated devices exist only on the CPU
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = SRC
     payload = json.dumps(

@@ -11,6 +11,8 @@ import sys
 
 sys.path.insert(0, "src")
 
+# CPU-only: the 8-device mesh is emulated, which only the CPU backend does
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import jax.numpy as jnp

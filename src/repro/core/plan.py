@@ -391,12 +391,16 @@ def _local_csr_cols(
 
 
 def _pick_bn(n_loc: int, pref: int = 256) -> int:
-    """Largest divisor of ``n_loc`` not exceeding ``pref``."""
+    """BSMM output tile width: all of ``n_loc`` when it fits in ``pref``,
+    else its largest divisor not exceeding ``pref`` — a multiple of 128
+    when one exists, since only those tile on TPU (the kernel refuses the
+    rest when compiled)."""
     if n_loc <= pref:
         return n_loc
-    for bn in range(pref, 0, -1):
-        if n_loc % bn == 0:
-            return bn
+    for step in (128, 1):
+        for bn in range(pref - pref % step, 0, -step):
+            if n_loc % bn == 0:
+                return bn
     return n_loc
 
 

@@ -66,11 +66,10 @@ from typing import Any, Callable, Literal
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import Mesh, PartitionSpec as P
 
-from jax.sharding import Mesh
-
-from repro.compat import shard_map
+from repro.kernels.tpu import interpret_mode
 
 __all__ = [
     "SummaConfig",
@@ -269,8 +268,8 @@ def _local_dot(a_panel, b_panel, accum, cfg: SummaConfig):
             if tiles else {}
         )
         prod = kops.tiled_matmul(
-            a_panel, b_panel, accum_dtype=cfg.accum_dtype, **tile_kw
-        ).astype(cfg.accum_dtype)
+            a_panel, b_panel, out_dtype=cfg.accum_dtype, **tile_kw
+        )
         return accum + prod
     prod = jnp.matmul(a_panel, b_panel, preferred_element_type=cfg.accum_dtype)
     return accum + prod
@@ -478,7 +477,7 @@ def _exec_sparse_bsmm(a_loc, b_loc, cols_loc, plan):
         bk=bk,
         bn=bn,
         out_dtype=cfg.accum_dtype,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )
     return c.astype(cfg.accum_dtype)
 
@@ -745,7 +744,7 @@ def _exec_ranksparse_grouped(u_loc, v_loc, b_loc, plan, *, r_pad: int):
         bk=bk,
         bn=bn,
         out_dtype=cfg.accum_dtype,
-        interpret=jax.default_backend() != "tpu",
+        interpret=interpret_mode(),
     )[:, :n_loc]
     y4 = y.reshape(live, mb_loc, r_pad, n_loc)
     u_g = jnp.stack(u_parts).reshape(live, mb_loc, bm, r_pad)

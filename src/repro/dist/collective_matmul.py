@@ -39,9 +39,8 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.compat import shard_map
 
 __all__ = ["project", "allgather_matmul"]
 

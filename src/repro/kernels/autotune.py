@@ -166,6 +166,9 @@ class KernelAutotuner:
     def _routes(self, key: tuple):
         """Build ``{route: (callable, args)}`` for a bucket; jit-wrapped.
 
+        Only routes that apply to the bucket are built (``factored`` needs
+        a rank), and every block they launch tiles on TPU, so a route
+        that fails to compile or run is an error, never a silent skip.
         The ``pallas`` route is parameterized by its tile triple, so it is
         returned as ``(tiles -> callable, args)`` and swept by ``tune``.
         """
@@ -189,8 +192,8 @@ class KernelAutotuner:
 
         routes["pallas"] = (pallas_fn, (a, b))
 
-        blk = min(bm, bk, 128)
-        mask = np.ones((bm // blk, bk // blk), dtype=bool)
+        # 128-wide blocks (or the whole bucket dim below that) tile on TPU
+        mask = np.ones((bm // min(bm, 128), bk // min(bk, 128)), dtype=bool)
         routes["bsmm"] = (
             jax.jit(lambda x, y: kops.bsmm(x, y, mask)), (a, b)
         )
@@ -238,23 +241,20 @@ class KernelAutotuner:
         for name, (fn, args) in built.items():
             if routes is not None and name not in routes:
                 continue
-            try:
-                if name == "pallas":
-                    best_t = float("inf")
-                    for t in TILE_CANDIDATES:
-                        cand = (min(t, bm), min(t, bk), min(t, bn))
-                        tt = _time_call(fn(cand), *args, repeats=repeats)
-                        if tt < best_t:
-                            best_t, tiles = tt, cand
-                        if cand == (bm, bk, bn):
-                            break  # larger candidates clamp to the same tiling
-                    times[name] = best_t
-                else:
-                    times[name] = _time_call(fn, *args, repeats=repeats)
-            except Exception:  # route inapplicable on this backend/shape
-                continue
+            if name == "pallas":
+                best_t = float("inf")
+                for t in TILE_CANDIDATES:
+                    cand = (min(t, bm), min(t, bk), min(t, bn))
+                    tt = _time_call(fn(cand), *args, repeats=repeats)
+                    if tt < best_t:
+                        best_t, tiles = tt, cand
+                    if cand == (bm, bk, bn):
+                        break  # larger candidates clamp to the same tiling
+                times[name] = best_t
+            else:
+                times[name] = _time_call(fn, *args, repeats=repeats)
         if not times:
-            raise ValueError(f"no route could be timed for bucket {key}")
+            raise ValueError(f"no requested route applies to bucket {key}")
         winner = min(times, key=times.get)
         entry = {
             "winner": winner,

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.kernels.tpu import check_blocks
 
 __all__ = ["bsmm_kernel", "bsmm_pallas"]
 
@@ -87,6 +87,10 @@ def bsmm_pallas(
         raise ValueError(
             f"col map rows {cols.shape[0]} != M blocks {m_blocks}"
         )
+    if not interpret:
+        check_blocks(
+            "bsmm", ((bm, bk), a.shape), ((bk, bn), b.shape), ((bm, bn), (m, n))
+        )
     out_dtype = out_dtype or a.dtype
     grid = (m_blocks, n // bn, s_steps)
 
@@ -112,7 +116,7 @@ def bsmm_pallas(
         functools.partial(bsmm_kernel, s_steps=s_steps),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -6,8 +6,9 @@ with ``pl.when`` (no MXU work, no VMEM traffic beyond the prefetch), which
 makes causal attention ~2× and sliding-window attention O(S·W) — the same
 "skip empty blocks" discipline as the block-sparse matmul kernel.
 
-Used by the serving path for prefill; ref.py::flash_attention_ref is the
-oracle.
+Reached through ``kernels.ops.flash_attention`` and the
+``attention(..., use_kernel=True)`` switch; serving prefill and training
+run the jnp path (``ref.py::flash_attention_ref``, also the oracle).
 """
 from __future__ import annotations
 
@@ -19,7 +20,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.kernels.tpu import check_blocks
 
 __all__ = ["flash_attention_pallas"]
 
@@ -121,6 +122,10 @@ def flash_attention_pallas(
         raise ValueError(f"seq {s}/{sk} must divide blocks ({bq},{bk})")
     if h % hkv:
         raise ValueError(f"q heads {h} must be a multiple of kv heads {hkv}")
+    if not interpret:
+        check_blocks(
+            "flash_attention", ((bq, dh), (s, dh)), ((bk, dh), (sk, dh))
+        )
     g = h // hkv
     scale_val = float(scale) if scale is not None else 1.0 / float(np.sqrt(dh))
     qf = q.reshape(b * h, s, dh)
@@ -155,7 +160,7 @@ def flash_attention_pallas(
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

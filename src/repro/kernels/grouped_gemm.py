@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.kernels.tpu import check_blocks
 
 __all__ = ["grouped_gemm_kernel", "grouped_gemm_pallas"]
 
@@ -63,6 +63,11 @@ def grouped_gemm_pallas(
         raise ValueError(f"shape must divide tiles ({bt},{bk},{bn})")
     if tile_expert.shape != (t // bt,):
         raise ValueError("tile_expert must have one entry per token tile")
+    if not interpret:
+        check_blocks(
+            "grouped_gemm",
+            ((bt, bk), x.shape), ((bk, bn), (d, f)), ((bt, bn), (t, f)),
+        )
     out_dtype = out_dtype or x.dtype
     k_tiles = d // bk
     grid = (t // bt, f // bn, k_tiles)
@@ -81,7 +86,7 @@ def grouped_gemm_pallas(
         functools.partial(grouped_gemm_kernel, k_tiles=k_tiles),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, f), out_dtype),
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

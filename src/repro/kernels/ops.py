@@ -3,7 +3,8 @@
 Handle shape padding, tile selection, dtype policy, and backend dispatch:
 on TPU the kernels run compiled; on CPU they run in ``interpret=True``
 mode (Python-level execution of the kernel body) so every test validates
-the *same* kernel code that targets the MXU.
+the *same* kernel code that targets the MXU.  There is no fallback: a
+shape the kernel cannot take raises, on either backend.
 """
 from __future__ import annotations
 
@@ -13,11 +14,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sparsity import block_csr_from_mask
-from repro.kernels import ref
 from repro.kernels.bsmm import bsmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.grouped_gemm import grouped_gemm_pallas
 from repro.kernels.tiled_matmul import tiled_matmul_pallas
+from repro.kernels.tpu import interpret_mode
 
 __all__ = [
     "tiled_matmul",
@@ -28,10 +29,6 @@ __all__ = [
 ]
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _pad2(x, mults):
     pads = [(0, -(-d // m) * m - d) for d, m in zip(x.shape, mults)]
     if any(p[1] for p in pads):
@@ -40,11 +37,11 @@ def _pad2(x, mults):
 
 
 def _pick_tile(dim: int, pref: int) -> int:
-    """Largest power-of-two tile <= pref that keeps padding reasonable."""
-    t = pref
-    while t > 8 and dim % t and dim < t:
-        t //= 2
-    return max(t, 8)
+    """Tile for one kernel dimension: the whole dimension when it fits in
+    ``pref`` (a full-extent block is legal on TPU at any size), else
+    ``pref`` with the dimension padded up to a multiple of it.  Callers
+    keep ``pref`` a multiple of 128 for the chip."""
+    return dim if dim <= pref else pref
 
 
 def tiled_matmul(
@@ -54,11 +51,10 @@ def tiled_matmul(
     bm: int = 256,
     bk: int = 256,
     bn: int = 256,
-    accum_dtype=jnp.float32,
     out_dtype=None,
 ) -> jax.Array:
-    """C = A @ B via the tiled Pallas kernel, auto-padded."""
-    del accum_dtype  # kernel always accumulates fp32
+    """C = A @ B via the tiled Pallas kernel (fp32 accumulation),
+    auto-padded; the result is ``out_dtype``, else A's dtype."""
     m, k = a.shape
     _, n = b.shape
     bm = _pick_tile(m, bm)
@@ -67,7 +63,7 @@ def tiled_matmul(
     a_p = _pad2(a, (bm, bk))
     b_p = _pad2(b, (bk, bn))
     c = tiled_matmul_pallas(
-        a_p, b_p, bm=bm, bk=bk, bn=bn, out_dtype=out_dtype, interpret=_interpret()
+        a_p, b_p, bm=bm, bk=bk, bn=bn, out_dtype=out_dtype, interpret=interpret_mode()
     )
     return c[:m, :n]
 
@@ -104,7 +100,7 @@ def bsmm(
         bk=bk_sz,
         bn=bn,
         out_dtype=out_dtype,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return c[:, :n]
 
@@ -143,7 +139,7 @@ def grouped_gemm(
         bk=bk,
         bn=bn,
         out_dtype=out_dtype,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )
     return y[:, :f]
 
@@ -191,7 +187,7 @@ def ranksparse_matmul(
         bk=bk_sz,
         bn=bn,
         out_dtype=jnp.float32,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )[:, :n]
     # stage 2: per-block U application + segment sum into C block rows
     y3 = y.reshape(csr.nnz, r_pad, n)
@@ -219,15 +215,14 @@ def flash_attention(
     bq: int = 256,
     bk: int = 256,
 ) -> jax.Array:
-    """Tiled online-softmax attention (forward)."""
-    s = q.shape[2]
-    bq = _pick_tile(s, bq)
+    """Tiled online-softmax attention (forward).
+
+    Sequence lengths longer than a block must be multiples of it; the
+    kernel raises otherwise (the jnp path, ``ref.flash_attention_ref``,
+    takes any length).
+    """
+    bq = _pick_tile(q.shape[2], bq)
     bk = _pick_tile(k.shape[2], bk)
-    if s % bq or k.shape[2] % bk:
-        # fall back to padded ref for awkward shapes (rare; serving pads)
-        return ref.flash_attention_ref(
-            q, k, v, causal=causal, window=window, scale=scale
-        )
     return flash_attention_pallas(
         q,
         k,
@@ -237,5 +232,5 @@ def flash_attention(
         scale=scale,
         bq=bq,
         bk=bk,
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )

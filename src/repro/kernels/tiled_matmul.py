@@ -20,7 +20,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import pallas_tpu_compiler_params
+from repro.kernels.tpu import check_blocks
 
 __all__ = ["tiled_matmul_kernel", "tiled_matmul_pallas"]
 
@@ -69,6 +69,11 @@ def tiled_matmul_pallas(
             f"shape ({m},{k},{n}) must divide tiles ({bm},{bk},{bn}); "
             "use kernels.ops.tiled_matmul for auto-padding"
         )
+    if not interpret:
+        check_blocks(
+            "tiled_matmul",
+            ((bm, bk), a.shape), ((bk, bn), b.shape), ((bm, bn), (m, n)),
+        )
     out_dtype = out_dtype or a.dtype
     k_tiles = k // bk
     grid = (m // bm, n // bn, k_tiles)
@@ -82,7 +87,7 @@ def tiled_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

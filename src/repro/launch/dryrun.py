@@ -260,9 +260,6 @@ def run_cell(
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        # jax < 0.5 returns a one-element list of dicts, newer jax a dict
-        if isinstance(cost, (list, tuple)):
-            cost = cost[0] if cost else None
         hlo_text = compiled.as_text()
     wc = hloa.analyze_hlo(hlo_text)
     chips = int(np.prod(list(mesh.shape.values())))

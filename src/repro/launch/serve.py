@@ -28,6 +28,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.dist.context import ParallelCtx
 from repro.dist.partitioning import param_shardings
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import init_model
 from repro.serve import engine
@@ -80,6 +81,7 @@ def main(argv=None):
     ap.add_argument("--plan-cache", default=None,
                     help="JSON path to load/save tuned plan winners")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family == "audio":

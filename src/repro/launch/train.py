@@ -24,6 +24,7 @@ import jax
 
 from repro.configs import get_config
 from repro.dist.context import ParallelCtx
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.train import checkpoint as ckpt
 from repro.train import train_step as ts
@@ -52,6 +53,7 @@ def main(argv=None):
     ap.add_argument("--log-every", type=int, default=10)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     mesh = make_host_mesh(args.dp, args.tp)

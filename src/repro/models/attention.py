@@ -1,9 +1,10 @@
 """GQA multi-head attention with RoPE / M-RoPE, causal + sliding window.
 
-Training uses the differentiable jnp path (XLA fuses it; remat bounds the
-S² logits).  Serving prefill uses the Pallas flash-attention kernel
-(forward-only).  TP: heads are sharded over ``ctx.tp_axis`` via sharding
-constraints; GSPMD inserts the corresponding collectives.
+Training and serving prefill both use the differentiable jnp path (XLA
+fuses it; remat bounds the S² logits); ``use_kernel=True`` swaps in the
+forward-only Pallas flash-attention kernel, which no driver sets today.
+TP: heads are sharded over ``ctx.tp_axis`` via sharding constraints;
+GSPMD inserts the corresponding collectives.
 """
 from __future__ import annotations
 

@@ -26,14 +26,12 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.sharding import PartitionSpec as P
 
 from repro.dist.context import ParallelCtx
 from repro.models import layers as L
 from repro.models.config import ModelConfig, MoEConfig
-
-from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 
 def padded_experts(moe: MoEConfig, ep: int) -> int:

@@ -31,9 +31,8 @@ import warnings
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-
-from repro.compat import shard_map
 
 from repro.dist.context import ParallelCtx
 from repro.models import layers as L
