@@ -12,11 +12,14 @@ examples use.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import blocking as bk
@@ -60,6 +63,12 @@ class DistributedMatmul:
     Each distinct (shapes, masks, strategy) builds its ``MatmulPlan``
     once; repeated (re)traces — scanned layers, prefill vs decode shapes
     — hit the cache instead of re-deriving the schedule.
+
+    Every call opens profiler spans, nested by time on the caller's
+    thread: ``repro.matmul`` (the whole call) around ``repro.plan`` (plan
+    lookup; ``repro.plan.build`` inside it on a miss), ``repro.pad``,
+    ``repro.execute`` (``core.summa``: ``repro.dispatch`` of a cached
+    program, or ``repro.compile`` on its first call) and ``repro.unpad``.
     """
 
     mesh: Mesh
@@ -83,7 +92,7 @@ class DistributedMatmul:
     )
     _cache_stats: dict = dataclasses.field(
         default_factory=lambda: {
-            "plan_hits": 0, "plan_misses": 0,
+            "plan_hits": 0, "plan_misses": 0, "plan_build_s": 0.0,
             "geom_hits": 0, "geom_misses": 0,
             "step_hits": 0, "step_misses": 0, "step_retraces": 0,
         },
@@ -116,6 +125,7 @@ class DistributedMatmul:
 
     # -- planning ------------------------------------------------------------
 
+    @functools.partial(annotate_function, name="repro.plan")
     def plan(
         self,
         m: int,
@@ -181,29 +191,32 @@ class DistributedMatmul:
         plan = self._plan_cache.get(key)
         if plan is None:
             self._cache_stats["plan_misses"] += 1
-            rank_map = a_ranks.rank_map() if rank_payload else a_ranks
-            b_rank_map = (
-                b_ranks.rank_map()
-                if isinstance(b_ranks, RankCSR)
-                else b_ranks
-            )
-            cfg = self.config(strategy)
-            if k_blocks is not None:
-                cfg = dataclasses.replace(cfg, k_blocks=int(k_blocks))
-            plan = plan_matmul(
-                m, k, n, cfg,
-                a_mask=a_mask, b_mask=b_mask, a_ranks=rank_map,
-                b_ranks=b_rank_map, c_mask=c_mask,
-                rank_payload=rank_payload, comm_mode=comm_mode,
-                stationarity=stationarity, itemsize=itemsize,
-                a_norms=a_norms, b_norms=b_norms, filter_eps=filter_eps,
-            )
-            if tune:
-                from repro.sched.tuner import tune_plan  # deferred: no cycle
+            t0 = time.perf_counter()
+            with TraceAnnotation("repro.plan.build"):
+                rank_map = a_ranks.rank_map() if rank_payload else a_ranks
+                b_rank_map = (
+                    b_ranks.rank_map()
+                    if isinstance(b_ranks, RankCSR)
+                    else b_ranks
+                )
+                cfg = self.config(strategy)
+                if k_blocks is not None:
+                    cfg = dataclasses.replace(cfg, k_blocks=int(k_blocks))
+                plan = plan_matmul(
+                    m, k, n, cfg,
+                    a_mask=a_mask, b_mask=b_mask, a_ranks=rank_map,
+                    b_ranks=b_rank_map, c_mask=c_mask,
+                    rank_payload=rank_payload, comm_mode=comm_mode,
+                    stationarity=stationarity, itemsize=itemsize,
+                    a_norms=a_norms, b_norms=b_norms, filter_eps=filter_eps,
+                )
+                if tune:
+                    from repro.sched.tuner import tune_plan  # deferred: no cycle
 
-                plan = tune_plan(plan)
-            if lookahead is not None:
-                plan = dataclasses.replace(plan, lookahead=int(lookahead))
+                    plan = tune_plan(plan)
+                if lookahead is not None:
+                    plan = dataclasses.replace(plan, lookahead=int(lookahead))
+            self._cache_stats["plan_build_s"] += time.perf_counter() - t0
             self._plan_cache[key] = plan
         else:
             self._cache_stats["plan_hits"] += 1
@@ -214,7 +227,8 @@ class DistributedMatmul:
     def cache_stats(self) -> dict:
         """Hit/miss/retrace counters for every cache on the hot path.
 
-        ``plan``: the ``MatmulPlan`` cache on this instance.  ``contract``:
+        ``plan``: the ``MatmulPlan`` cache on this instance, with
+        ``build_s`` the seconds spent building plans on misses.  ``contract``:
         the matricization-geometry cache (``geom_*``) and the compiled
         contraction-step programs (``step_*`` — ``step_retraces`` counts
         actual jax traces, which must equal ``step_misses`` when keys are
@@ -226,6 +240,7 @@ class DistributedMatmul:
             "plan": {
                 "size": len(self._plan_cache),
                 "hits": s["plan_hits"], "misses": s["plan_misses"],
+                "build_s": s["plan_build_s"],
             },
             "contract": {
                 "size": len(self._contract_cache),
@@ -243,6 +258,7 @@ class DistributedMatmul:
 
     # -- call paths ----------------------------------------------------------
 
+    @functools.partial(annotate_function, name="repro.matmul")
     def __call__(
         self,
         a: jax.Array | None,
@@ -317,11 +333,17 @@ class DistributedMatmul:
             comm_mode=comm_mode, stationarity=stationarity,
             a_norms=a_norms, b_norms=b_norms, filter_eps=filter_eps,
         )
+        return self._run(a, b, plan)
+
+    def _run(self, a: jax.Array, b: jax.Array, plan: MatmulPlan) -> jax.Array:
+        """Pad to the plan's shapes, execute, crop to (M, N)."""
         (mp, kp), (_, np_) = plan.padded_shapes
-        a_p = _pad_to_shape(a, (mp, kp))
-        b_p = _pad_to_shape(b, (kp, np_))
+        with TraceAnnotation("repro.pad"):
+            a_p = _pad_to_shape(a, (mp, kp))
+            b_p = _pad_to_shape(b, (kp, np_))
         c_p = sm.execute_plan(a_p, b_p, plan, compiled=self.compiled)
-        return c_p[:m, :n]
+        with TraceAnnotation("repro.unpad"):
+            return c_p[: a.shape[0], : b.shape[1]]
 
     # -- tensor contractions -------------------------------------------------
 
@@ -381,20 +403,18 @@ class DistributedMatmul:
             comm_mode=comm_mode, stationarity=stationarity,
             a_norms=a_norms, b_norms=b_norms, filter_eps=filter_eps,
         )
-        (mp, kp), (_, np_) = plan.padded_shapes
-        b_p = _pad_to_shape(b, (kp, np_))
         if plan.local_impl != "ranksparse":
             # factor layout does not fit this grid: densify and run the
             # planned masked DAG (correct, mask-level pruning only)
-            a_p = _pad_to_shape(jnp.asarray(a_ranks.to_dense()), (mp, kp))
-            c_p = sm.execute_plan(a_p, b_p, plan, compiled=self.compiled)
+            return self._run(jnp.asarray(a_ranks.to_dense()), b, plan)
+        (_, kp), (_, np_) = plan.padded_shapes
+        with TraceAnnotation("repro.pad"):
+            b_p = _pad_to_shape(b, (kp, np_))
+            u_all, v_all = sm.rank_operands(a_ranks, plan)
+            u, v = jnp.asarray(u_all), jnp.asarray(v_all)
+        c_p = sm.execute_rank_plan(u, v, b_p, plan, compiled=self.compiled)
+        with TraceAnnotation("repro.unpad"):
             return c_p[:m, :n]
-        u_all, v_all = sm.rank_operands(a_ranks, plan)
-        c_p = sm.execute_rank_plan(
-            jnp.asarray(u_all), jnp.asarray(v_all), b_p, plan,
-            compiled=self.compiled,
-        )
-        return c_p[:m, :n]
 
 
 @dataclasses.dataclass
@@ -530,16 +550,18 @@ class NonuniformMatmul:
             raise ValueError(f"A shape {a.shape} mismatches tilings")
         if b.shape != (self.inner_tiling.extent, self.col_tiling.extent):
             raise ValueError(f"B shape {b.shape} mismatches tilings")
-        a_p = self._expand(self._expand(a, self.row_b, 0), self.inner_b, 1)
-        b_p = self._expand(self._expand(b, self.inner_b, 0), self.col_b, 1)
-        c_p = self.mm(
-            a_p,
-            b_p,
-            a_ranks=(
-                self.physical_rank_map(a_ranks)
-                if a_ranks is not None else None
-            ),
-            lookahead=lookahead,
-            tune=tune,
-        )
-        return self._compact(c_p)
+        with TraceAnnotation("repro.matmul"):
+            with TraceAnnotation("repro.pad"):
+                a_p = self._expand(
+                    self._expand(a, self.row_b, 0), self.inner_b, 1
+                )
+                b_p = self._expand(
+                    self._expand(b, self.inner_b, 0), self.col_b, 1
+                )
+            plan = self.plan(
+                a_ranks=a_ranks, itemsize=a.dtype.itemsize,
+                lookahead=lookahead, tune=tune,
+            )
+            c_p = self.mm._run(a_p, b_p, plan)
+            with TraceAnnotation("repro.unpad"):
+                return self._compact(c_p)

@@ -60,13 +60,16 @@ of both grid dims unless it equals them).  Over-decomposition (paper
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
+import time
 from typing import Any, Callable, Literal
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation, annotate_function
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.tpu import interpret_mode
@@ -770,7 +773,9 @@ _EXEC_IMPLS: dict[str, Callable] = {
 #: same plan dispatch a cached compiled program instead of re-tracing the
 #: interpreter loop op by op.
 _EXEC_CACHE: dict = {}
-_EXEC_STATS = {"hits": 0, "misses": 0, "retraces": 0}
+_EXEC_STATS = {
+    "hits": 0, "misses": 0, "retraces": 0, "build_s": 0.0, "trace_s": 0.0,
+}
 
 
 def executable_cache_stats() -> dict:
@@ -779,7 +784,10 @@ def executable_cache_stats() -> dict:
     ``retraces`` counts actual jax trace executions of cached wrappers —
     with stable plan digests and dtypes it must equal ``misses`` (every
     program traced exactly once); a retrace without a miss means a cache
-    key is unstable."""
+    key is unstable.  ``build_s`` sums the wall seconds of every new
+    executable's first call (trace, lowering, compile or persistent-cache
+    load, first enqueue); ``trace_s`` is the part of it spent in the
+    traced Python body."""
     return {**_EXEC_STATS, "size": len(_EXEC_CACHE)}
 
 
@@ -805,16 +813,35 @@ def _autotune_key_suffix() -> tuple:
     return (fp,) if fp else ()
 
 
-def _cached_executable(key: tuple, build: Callable) -> Callable:
+def _run_cached(key: tuple, body: Callable, *args) -> jax.Array:
+    """``body(*args)`` through the cached jitted program for ``key``.
+
+    A hit dispatches the program inside a ``repro.dispatch`` span.  A miss
+    jits ``body`` and makes the first call inside ``repro.compile``
+    instead, adding its wall time to ``build_s`` and the time spent in
+    ``body`` while tracing to ``trace_s``; hits are not timed."""
     key = key + _autotune_key_suffix()
     fn = _EXEC_CACHE.get(key)
-    if fn is None:
-        _EXEC_STATS["misses"] += 1
-        fn = build()
-        _EXEC_CACHE[key] = fn
-    else:
+    if fn is not None:
         _EXEC_STATS["hits"] += 1
-    return fn
+        with TraceAnnotation("repro.dispatch"):
+            return fn(*args)
+    _EXEC_STATS["misses"] += 1
+
+    def traced(*xs):
+        _EXEC_STATS["retraces"] += 1
+        t0 = time.perf_counter()
+        try:
+            return body(*xs)
+        finally:
+            _EXEC_STATS["trace_s"] += time.perf_counter() - t0
+
+    fn = _EXEC_CACHE[key] = jax.jit(traced)
+    t0 = time.perf_counter()
+    with TraceAnnotation("repro.compile"):
+        out = fn(*args)
+    _EXEC_STATS["build_s"] += time.perf_counter() - t0
+    return out
 
 
 def warm_plan_executable(plan, dtype, *, out_dtype: Any | None = None):
@@ -840,6 +867,7 @@ def warm_plan_executable(plan, dtype, *, out_dtype: Any | None = None):
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(annotate_function, name="repro.execute")
 def execute_plan(
     a: jax.Array,
     b: jax.Array,
@@ -860,7 +888,8 @@ def execute_plan(
     the compiled program (XLA-managed buffers, freed on exit); operand
     buffers are deliberately *not* donated, since callers routinely reuse
     them across timing iterations.  Inside an enclosing ``jax.jit`` the
-    interpreter body inlines into the caller's trace unchanged.
+    interpreter body inlines into the caller's trace unchanged.  The
+    whole call is the ``repro.execute`` span.
     """
     _check_plan_operands(a, b, plan)
     out_dtype = jnp.dtype(out_dtype or a.dtype)
@@ -870,15 +899,11 @@ def execute_plan(
         "plan", plan.digest(), plan.local_impl, plan.resolve_lookahead(),
         str(a.dtype), str(b.dtype), str(out_dtype),
     )
-
-    def build():
-        def traced(a, b):
-            _EXEC_STATS["retraces"] += 1
-            return _execute_plan_eager(a, b, plan, out_dtype=out_dtype)
-
-        return jax.jit(traced)
-
-    return _cached_executable(key, build)(a, b)
+    return _run_cached(
+        key,
+        lambda a, b: _execute_plan_eager(a, b, plan, out_dtype=out_dtype),
+        a, b,
+    )
 
 
 def _check_plan_operands(a, b, plan) -> None:
@@ -1032,6 +1057,7 @@ def rank_operands(a_ranks, plan) -> tuple[np.ndarray, np.ndarray]:
     return u_all, v_all
 
 
+@functools.partial(annotate_function, name="repro.execute")
 def execute_rank_plan(
     u: jax.Array,
     v: jax.Array,
@@ -1055,7 +1081,8 @@ def execute_rank_plan(
     + operand shapes/dtypes.  The factors are *runtime arguments*, never
     trace constants — the digest (like ``plan.rank_key``) sees only the
     rank structure, so baking values in would silently serve stale
-    factors to a same-structure payload.
+    factors to a same-structure payload.  The whole call is the
+    ``repro.execute`` span.
     """
     out_dtype = jnp.dtype(out_dtype or b.dtype)
     if compiled and not _is_traced(u, v, b):
@@ -1065,17 +1092,13 @@ def execute_rank_plan(
             u.shape, v.shape, str(u.dtype), str(v.dtype), str(b.dtype),
             str(out_dtype),
         )
-
-        def build():
-            def traced(u, v, b):
-                _EXEC_STATS["retraces"] += 1
-                return _execute_rank_plan_eager(
-                    u, v, b, plan, out_dtype=out_dtype
-                )
-
-            return jax.jit(traced)
-
-        return _cached_executable(key, build)(u, v, b)
+        return _run_cached(
+            key,
+            lambda u, v, b: _execute_rank_plan_eager(
+                u, v, b, plan, out_dtype=out_dtype
+            ),
+            u, v, b,
+        )
     return _execute_rank_plan_eager(u, v, b, plan, out_dtype=out_dtype)
 
 
@@ -1263,15 +1286,7 @@ def summa_25d_matmul(
         "25d", plan.digest(), rep_axis, per_rep,
         str(a.dtype), str(b.dtype), str(out_dtype),
     )
-
-    def build():
-        def traced(a, b):
-            _EXEC_STATS["retraces"] += 1
-            return run(a, b)
-
-        return jax.jit(traced)
-
-    return _cached_executable(key, build)(a, b)
+    return _run_cached(key, run, a, b)
 
 
 # ---------------------------------------------------------------------------

@@ -120,4 +120,5 @@ def bsmm_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="bsmm",
     )(cols, a, b)

@@ -164,5 +164,6 @@ def flash_attention_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention",
     )(qf, kf, vf)
     return out.reshape(b, h, s, dh)

@@ -90,4 +90,5 @@ def grouped_gemm_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="grouped_gemm",
     )(tile_expert, x, w)

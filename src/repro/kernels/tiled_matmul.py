@@ -91,4 +91,5 @@ def tiled_matmul_pallas(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="tiled_matmul",
     )(a, b)

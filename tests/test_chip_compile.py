@@ -10,6 +10,7 @@ import: only one process may load the TPU library, and every test worker
 imports this file.  Keep these tests in this one file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -50,8 +51,14 @@ def _spec(sharding, shape, dtype=jnp.bfloat16):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _assert_kernel(lowered):
-    assert "tpu_custom_call" in lowered.compile().as_text()
+def _assert_kernel(lowered, name):
+    """The compiled program runs the kernel as a TPU custom call named
+    ``name``, the name the profiler trace shows for it."""
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = \S+ custom-call\(", text), (
+        f"no custom call named {name!r} in the compiled program"
+    )
 
 
 def test_tiled_matmul_compiles(one_chip):
@@ -60,7 +67,8 @@ def test_tiled_matmul_compiles(one_chip):
         tiled_matmul_pallas.lower(
             a, a, bm=256, bk=256, bn=256, out_dtype=jnp.float32,
             interpret=False,
-        )
+        ),
+        "tiled_matmul",
     )
 
 
@@ -73,7 +81,8 @@ def test_bsmm_compiles(one_chip):
         bsmm_pallas.lower(
             a, a, cols, bm=256, bk=256, bn=256, out_dtype=jnp.float32,
             interpret=False,
-        )
+        ),
+        "bsmm",
     )
 
 
@@ -84,7 +93,8 @@ def test_flash_attention_compiles(one_chip):
     _assert_kernel(
         flash_attention_pallas.lower(
             q, kv, kv, causal=True, bq=256, bk=256, interpret=False
-        )
+        ),
+        "flash_attention",
     )
 
 
@@ -95,7 +105,8 @@ def test_grouped_gemm_compiles(one_chip):
     _assert_kernel(
         grouped_gemm_pallas.lower(
             x, w, te, bt=256, bk=256, bn=256, interpret=False
-        )
+        ),
+        "grouped_gemm",
     )
 
 

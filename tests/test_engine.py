@@ -220,13 +220,16 @@ def test_cache_stats_shape_and_reset():
     mm = _mm()
     stats = mm.cache_stats()
     assert set(stats) == {"plan", "contract", "executable"}
-    assert set(stats["plan"]) == {"size", "hits", "misses"}
+    assert set(stats["plan"]) == {"size", "hits", "misses", "build_s"}
     assert {"geom_hits", "geom_misses", "step_hits", "step_misses",
             "step_retraces"} <= set(stats["contract"])
-    assert {"hits", "misses", "retraces", "size"} <= set(stats["executable"])
+    assert {"hits", "misses", "retraces", "size", "build_s",
+            "trace_s"} <= set(stats["executable"])
     rng = np.random.default_rng(3)
     mm.plan(64, 64, 64, b_mask=rng.random((4, 4)) < 0.5)
     assert mm.cache_stats()["plan"]["misses"] == 1
+    assert mm.cache_stats()["plan"]["build_s"] > 0
     mm.reset_cache_stats()
     s = mm.cache_stats()
     assert s["plan"]["hits"] == 0 and s["plan"]["misses"] == 0
+    assert s["plan"]["build_s"] == 0
