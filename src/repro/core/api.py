@@ -233,8 +233,11 @@ class DistributedMatmul:
         contraction-step programs (``step_*`` — ``step_retraces`` counts
         actual jax traces, which must equal ``step_misses`` when keys are
         stable).  ``executable``: the process-wide plan-digest-keyed
-        executable cache in ``core.summa``.
+        executable cache in ``core.summa``.  ``kernel``: the process-wide
+        count of ``tiled_matmul``'s tile choices, ``"MxKxN->bmxbkxbn"``.
         """
+        from repro.kernels.ops import tile_choice_stats
+
         s = self._cache_stats
         return {
             "plan": {
@@ -249,6 +252,7 @@ class DistributedMatmul:
                 "step_retraces": s["step_retraces"],
             },
             "executable": sm.executable_cache_stats(),
+            "kernel": tile_choice_stats(),
         }
 
     def reset_cache_stats(self) -> None:

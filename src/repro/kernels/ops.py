@@ -8,6 +8,7 @@ shape the kernel cannot take raises, on either backend.
 """
 from __future__ import annotations
 
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +18,19 @@ from repro.core.sparsity import block_csr_from_mask
 from repro.kernels.bsmm import bsmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.grouped_gemm import grouped_gemm_pallas
-from repro.kernels.tiled_matmul import tiled_matmul_pallas
+from repro.kernels.tiled_matmul import (
+    DEFAULT_BK,
+    DEFAULT_BM,
+    DEFAULT_BN,
+    tiled_matmul_pallas,
+    vmem_bytes,
+)
 from repro.kernels.tpu import interpret_mode
 
 __all__ = [
     "tiled_matmul",
+    "choose_tiles",
+    "tile_choice_stats",
     "bsmm",
     "grouped_gemm",
     "ranksparse_matmul",
@@ -44,22 +53,73 @@ def _pick_tile(dim: int, pref: int) -> int:
     return dim if dim <= pref else pref
 
 
+#: ``tiled_matmul``'s block triples for large panels, fastest first: a
+#: triple is taken where each block divides its operand dimension and the
+#: launch's VMEM (``vmem_bytes``) fits ``TILE_VMEM_BUDGET``.  From the
+#: chip sweep of ``scripts/tile_sweep.py`` (PERF.md, section 6): on a v5e
+#: at 32768^3 and 24576^3 the first reaches 98% of the MXU's peak and the
+#: others take 1%, 4%, 8% and 32% longer; the budget is the first's VMEM.
+LARGE_TILES = (
+    (1024, 1024, 2048),
+    (1024, 1024, 1024),
+    (512, 1024, 1024),
+    (512, 512, 1024),
+    (512, 512, 512),
+)
+TILE_VMEM_BUDGET = 36 * 2**20
+
+#: ``"MxKxN->bmxbkxbn"`` -> times ``tiled_matmul`` chose those tiles (each
+#: trace, under jit), process-wide; ``DistributedMatmul.cache_stats()``
+#: reports it as ``"kernel"``.
+_TILE_CHOICES: collections.Counter = collections.Counter()
+
+
+def choose_tiles(
+    m: int, k: int, n: int, in_itemsize: int, out_itemsize: int
+) -> tuple[int, int, int]:
+    """``tiled_matmul``'s ``(bm, bk, bn)`` for an ``(m, k) @ (k, n)``
+    product: the first of ``LARGE_TILES`` that divides the shape exactly
+    within the VMEM budget, else the kernel's default 256-wide blocks as
+    ``_pick_tile`` gives them (the whole dimension at or under 256, else
+    256 with padding)."""
+    for bm, bk, bn in LARGE_TILES:
+        fits = vmem_bytes(bm, bk, bn, in_itemsize, out_itemsize) <= TILE_VMEM_BUDGET
+        if fits and not (m % bm or k % bk or n % bn):
+            return bm, bk, bn
+    return tuple(
+        _pick_tile(d, t) for d, t in zip((m, k, n), (DEFAULT_BM, DEFAULT_BK, DEFAULT_BN))
+    )
+
+
+def tile_choice_stats() -> dict:
+    """``tiled_matmul``'s tile choices so far, ``{"MxKxN->bmxbkxbn": count}``."""
+    return dict(_TILE_CHOICES)
+
+
 def tiled_matmul(
     a: jax.Array,
     b: jax.Array,
     *,
-    bm: int = 256,
-    bk: int = 256,
-    bn: int = 256,
+    bm: int | None = None,
+    bk: int | None = None,
+    bn: int | None = None,
     out_dtype=None,
 ) -> jax.Array:
     """C = A @ B via the tiled Pallas kernel (fp32 accumulation),
-    auto-padded; the result is ``out_dtype``, else A's dtype."""
+    auto-padded; the result is ``out_dtype``, else A's dtype.  Tiles left
+    ``None`` come from :func:`choose_tiles`."""
     m, k = a.shape
     _, n = b.shape
+    out_dtype = out_dtype or a.dtype
+    if None in (bm, bk, bn):
+        cm, ck, cn = choose_tiles(
+            m, k, n, a.dtype.itemsize, jnp.dtype(out_dtype).itemsize
+        )
+        bm, bk, bn = bm or cm, bk or ck, bn or cn
     bm = _pick_tile(m, bm)
     bk = _pick_tile(k, bk)
     bn = _pick_tile(n, bn)
+    _TILE_CHOICES[f"{m}x{k}x{n}->{bm}x{bk}x{bn}"] += 1
     a_p = _pad2(a, (bm, bk))
     b_p = _pad2(b, (bk, bn))
     c = tiled_matmul_pallas(

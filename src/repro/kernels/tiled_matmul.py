@@ -9,7 +9,9 @@ exactly like the paper's decomposed rank-k-update tasks.
 
 Block shapes are MXU-aligned (multiples of 128 on the minor dims by
 default); fp32 accumulation in VMEM scratch; output cast to the operand
-dtype.
+dtype.  A launch whose blocks need more VMEM than Mosaic's default scoped
+limit asks for what they need (:func:`vmem_bytes`) and a quarter more for
+Mosaic's own scratch, up to ``MAX_VMEM``.
 """
 from __future__ import annotations
 
@@ -22,11 +24,26 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.tpu import check_blocks
 
-__all__ = ["tiled_matmul_kernel", "tiled_matmul_pallas"]
+__all__ = ["tiled_matmul_kernel", "tiled_matmul_pallas", "vmem_bytes"]
 
 DEFAULT_BM = 256
 DEFAULT_BK = 256
 DEFAULT_BN = 256
+
+#: VMEM Mosaic gives a kernel on a v5e core unless it asks for more.
+DEFAULT_SCOPED_VMEM = 16 * 2**20
+#: The most VMEM one launch asks for, of a v5e core's 128 MiB.
+MAX_VMEM = 64 * 2**20
+
+
+def vmem_bytes(bm: int, bk: int, bn: int, in_itemsize: int, out_itemsize: int) -> int:
+    """VMEM one launch holds: double-buffered A and B blocks, the
+    double-buffered C block and the fp32 accumulator."""
+    return (
+        2 * (bm * bk + bk * bn) * in_itemsize
+        + 2 * bm * bn * out_itemsize
+        + 4 * bm * bn
+    )
 
 
 def tiled_matmul_kernel(a_ref, b_ref, c_ref, acc_ref, *, k_tiles: int):
@@ -75,6 +92,15 @@ def tiled_matmul_pallas(
             ((bm, bk), a.shape), ((bk, bn), b.shape), ((bm, bn), (m, n)),
         )
     out_dtype = out_dtype or a.dtype
+    need = vmem_bytes(
+        bm, bk, bn, a.dtype.itemsize, jnp.dtype(out_dtype).itemsize
+    )
+    if need > MAX_VMEM:
+        raise ValueError(
+            f"tiles ({bm},{bk},{bn}) need {need} bytes of VMEM, "
+            f"over the {MAX_VMEM} a launch may ask for"
+        )
+    limit = need + need // 4  # Mosaic's own scratch: 2-12% more, v5e
     k_tiles = k // bk
     grid = (m // bm, n // bn, k_tiles)
     return pl.pallas_call(
@@ -89,6 +115,9 @@ def tiled_matmul_pallas(
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=(
+                min(limit, MAX_VMEM) if limit > DEFAULT_SCOPED_VMEM else None
+            ),
         ),
         interpret=interpret,
         name="tiled_matmul",

@@ -23,6 +23,7 @@ from repro.core.sparsity import block_csr_from_mask, random_block_mask
 from repro.kernels.bsmm import bsmm_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.grouped_gemm import grouped_gemm_pallas
+from repro.kernels.ops import LARGE_TILES, choose_tiles
 from repro.kernels.tiled_matmul import tiled_matmul_pallas
 
 
@@ -66,6 +67,23 @@ def test_tiled_matmul_compiles(one_chip):
     _assert_kernel(
         tiled_matmul_pallas.lower(
             a, a, bm=256, bk=256, bn=256, out_dtype=jnp.float32,
+            interpret=False,
+        ),
+        "tiled_matmul",
+    )
+
+
+@pytest.mark.parametrize("n", [32768, 24576])
+def test_tiled_matmul_compiles_with_chosen_tiles(one_chip, n):
+    """Each cell's local product (one chip: 32768^3; a chip of the 2x2
+    mesh: 24576^3 panels) with the triple the tile rule picks, fp32 C:
+    Mosaic takes the blocks and they fit the launch's scoped VMEM."""
+    bm, bk, bn = choose_tiles(n, n, n, 2, 4)
+    assert (bm, bk, bn) in LARGE_TILES
+    a = _spec(one_chip, (n, n))
+    _assert_kernel(
+        tiled_matmul_pallas.lower(
+            a, a, bm=bm, bk=bk, bn=bn, out_dtype=jnp.float32,
             interpret=False,
         ),
         "tiled_matmul",

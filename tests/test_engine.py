@@ -10,6 +10,7 @@ via the observable counters (``DistributedMatmul.cache_stats()`` /
 any machine.
 """
 import dataclasses
+import json
 
 import numpy as np
 import pytest
@@ -216,10 +217,43 @@ def test_contract_chain_repeat_is_all_hits():
 # ---------------------------------------------------------------------------
 
 
+TILE_COUNTER_CODE = """
+import json
+import jax.numpy as jnp
+import numpy as np
+from repro.core import DistributedMatmul
+from repro.launch.mesh import make_host_mesh
+mm = DistributedMatmul(
+    make_host_mesh({rows}, {cols}), strategy="taskbased",
+    local_matmul="pallas", accum_dtype=jnp.float32,
+)
+a = jnp.asarray(np.random.default_rng(0).normal(size=(2048, 2048)), jnp.bfloat16)
+for _ in range(2):
+    mm(a, a).block_until_ready()
+    print("KERNEL " + json.dumps(mm.cache_stats()["kernel"]))
+"""
+
+
+@pytest.mark.parametrize(
+    "grid,want",
+    [
+        ((1, 1), {"2048x2048x2048->1024x1024x2048": 1}),
+        # two 1024-wide K panels a chip, each traced once
+        ((2, 2), {"1024x1024x1024->1024x1024x1024": 2}),
+    ],
+)
+def test_cache_stats_report_tile_choices(subproc, grid, want):
+    """``cache_stats()["kernel"]`` counts the local kernel's tile choice
+    per trace: the first call adds it, the cached second call does not."""
+    out = subproc(TILE_COUNTER_CODE.format(rows=grid[0], cols=grid[1]), devices=4)
+    lines = [ln for ln in out.splitlines() if ln.startswith("KERNEL ")]
+    assert [json.loads(ln[len("KERNEL "):]) for ln in lines] == [want, want]
+
+
 def test_cache_stats_shape_and_reset():
     mm = _mm()
     stats = mm.cache_stats()
-    assert set(stats) == {"plan", "contract", "executable"}
+    assert set(stats) == {"plan", "contract", "executable", "kernel"}
     assert set(stats["plan"]) == {"size", "hits", "misses", "build_s"}
     assert {"geom_hits", "geom_misses", "step_hits", "step_misses",
             "step_retraces"} <= set(stats["contract"])

@@ -5,6 +5,7 @@ import pytest
 
 from repro.core.sparsity import random_block_mask
 from repro.kernels import ops, ref
+from repro.kernels.tiled_matmul import vmem_bytes
 
 RNG = np.random.default_rng(0)
 
@@ -29,6 +30,60 @@ def test_tiled_matmul(m, k, n, dtype):
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         rtol=_tol(dtype), atol=_tol(dtype) * k ** 0.5,
+    )
+
+
+@pytest.mark.parametrize(
+    "shape,itemsizes,want",
+    [
+        # large aligned panels: the cells' local products, bf16 in, fp32 out
+        ((32768, 32768, 32768), (2, 4), (1024, 1024, 2048)),
+        ((24576, 24576, 24576), (2, 4), (1024, 1024, 2048)),
+        ((3072, 1024, 3072), (2, 4), (1024, 1024, 1024)),
+        ((1536, 1024, 1024), (2, 4), (512, 1024, 1024)),
+        ((4096, 1536, 2048), (2, 4), (512, 512, 1024)),
+        ((1536, 512, 1536), (2, 2), (512, 512, 512)),
+        # fp32 operands need more VMEM: the first triple is over budget
+        ((4096, 4096, 4096), (4, 4), (1024, 1024, 1024)),
+        # at or under 256, or divided by no large triple: today's tiles
+        ((256, 256, 256), (2, 4), (256, 256, 256)),
+        ((100, 60, 36), (4, 4), (100, 60, 36)),
+        ((256, 32768, 32768), (2, 4), (256, 256, 256)),
+        ((32768, 256, 32768), (2, 4), (256, 256, 256)),
+        ((768, 768, 768), (2, 4), (256, 256, 256)),
+        ((1000, 2048, 2048), (2, 4), (256, 256, 256)),
+        ((300, 200, 4096), (2, 4), (256, 200, 256)),
+    ],
+)
+def test_choose_tiles(shape, itemsizes, want):
+    got = ops.choose_tiles(*shape, *itemsizes)
+    assert got == want
+    assert vmem_bytes(*got, *itemsizes) <= ops.TILE_VMEM_BUDGET
+
+
+@pytest.mark.parametrize(
+    "shape,tiles,choice",
+    [
+        ((1024, 2048, 2048), None, "1024x2048x2048->1024x1024x2048"),
+        ((1024, 2048, 2048), (512, 1024, 1024), "1024x2048x2048->512x1024x1024"),
+        ((1024, 2048, 2048), (256, 256, 256), "1024x2048x2048->256x256x256"),
+        ((300, 200, 520), None, "300x200x520->256x200x256"),
+    ],
+)
+def test_tiled_matmul_tile_choice(shape, tiles, choice):
+    """The rule's triple, or the caller's, runs and is counted: 2 grid
+    steps of the rule's blocks, 8 of (512, 1024, 1024); padding where 256
+    does not divide."""
+    m, k, n = shape
+    a, b = _arr((m, k), jnp.bfloat16), _arr((k, n), jnp.bfloat16)
+    kw = dict(zip(("bm", "bk", "bn"), tiles)) if tiles else {}
+    before = ops.tile_choice_stats().get(choice, 0)
+    out = ops.tiled_matmul(a, b, out_dtype=jnp.float32, **kw)
+    assert ops.tile_choice_stats()[choice] == before + 1
+    want = ref.matmul_ref(a, b, out_dtype=jnp.float32)
+    assert out.shape == (m, n) and out.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(want), rtol=1e-5, atol=1e-3
     )
 
 
