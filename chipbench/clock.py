@@ -12,10 +12,13 @@ time + ``delta``) in every product of the window:
    ``delta <= done - last op's end``.
 
 The tightest bracket over the window's products is returned, with its
-midpoint.  The products are the engine's ``repro.execute`` spans in the
+midpoint.  The products are the engine's ``repro.matmul`` spans in the
 window, or, for a program without them, the benchmark's ``chipbench.call``
-spans; a chip's operations are split into as many products at their
-widest idle gaps (the loop is closed: one product at a time).
+spans; a chip's operations are split into as many products, by count
+where they divide evenly, else at their widest idle gaps (the loop is
+closed: one product at a time).  A whole
+call, not its ``repro.execute``: a nonuniform call enqueues its padding
+gathers before the product, and they open its run on the chip.
 
 Launch, tightest first: the runtime's ``DoEnqueueProgram`` (earliest over
 its threads, as it is not known which thread served which chip), its
@@ -61,9 +64,18 @@ def _in_window(spans, window):
 
 
 def products(ops, n: int):
-    """A chip's busy intervals split into ``n`` products at the ``n - 1``
-    widest idle gaps: ``[(first start, last end)]``, or None when the chip
-    has fewer than ``n`` busy intervals."""
+    """A chip's operations split into ``n`` products: ``[(first start, last
+    end)]``.  Every call of the closed loop runs the same programs, so
+    where the chip ran a multiple of ``n`` operations, each product is the
+    next ``len(ops) // n`` of them by start: a host stall inside a call
+    can leave an idle gap wider than those between calls.  Else the busy
+    intervals are split at the ``n - 1`` widest idle gaps; None when the
+    chip has fewer than ``n`` of them."""
+    if n >= 1 and ops and len(ops) % n == 0:
+        ordered = sorted(ops, key=lambda e: e.start)
+        per = len(ops) // n
+        groups = [ordered[i * per:(i + 1) * per] for i in range(n)]
+        return [(min(e.start for e in g), max(e.end for e in g)) for g in groups]
     busy = xplane.union([(e.start, e.end) for e in ops])
     if n < 1 or len(busy) < n:
         return None
@@ -76,7 +88,7 @@ def products(ops, n: int):
 def _host_products(trace, window):
     """Per product of the window: its host interval, from its anchor's start
     to the next anchor's start (the window's end for the last)."""
-    anchors = _in_window(trace.spans("repro.execute"), window)
+    anchors = _in_window(trace.spans("repro.matmul"), window)
     if not anchors:
         anchors = _in_window(trace.spans("chipbench.call"), window)
     starts = [e.start for e in anchors]

@@ -8,7 +8,8 @@ For each of ``--seeds`` it drives a run of the cell (set-up, a short
 window at the cell's own size and load, the comparison) and prints the
 numbers compared.  For each of ``--control-seeds`` it puts the control in
 the program's place, the reference computed from int8 operands
-(``reference.int8_control``), and prints the same numbers.  The lower
+(``reference.int8_control``, through the cell's entry, on the operands
+the entry makes), and prints the same numbers.  The lower
 reading of a number is the largest the program gives, the upper one the
 smallest the control gives.  One JSON line per reading; the benchmark's
 own runs never run this.
@@ -45,7 +46,6 @@ def main(argv=None) -> int:
     bench, cell, config, traffic = run.load_cell(args.workload)
     devices = run.require_devices(int(cell["chips"]))
     run.log(f"compile cache {run.enable_compile_cache()}")
-    from chipbench import generate, reference
 
     def emit(kind: str, seed: int, values: dict, **extra) -> None:
         line = {"workload": cell["name"], "kind": kind, "seed": seed, **values, **extra}
@@ -60,12 +60,13 @@ def main(argv=None) -> int:
         emit("program", seed, {k: v["value"] for k, v in res["checks"].items()},
              correct=res["correct"], calls=res["attempted"])
     mesh = run.make_mesh(config, devices)
-    block = int(config["block"])
+    entry = run.entry_of(config)
     for seed in args.control_seeds:
-        a, b = generate.make_operands(config, seed, mesh)
-        c = reference.int8_control(a, b, block, config["out_dtype"], mesh)
-        values = reference.compare(a, b, c, block, mesh)
-        del a, b, c
+        product = entry.build(config, traffic, seed, mesh)
+        product.drop()
+        c = product.control()
+        values = product.compare(c)
+        del product, c
         emit("int8_control", seed, values)
     return 0
 

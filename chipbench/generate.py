@@ -1,16 +1,24 @@
-"""The one generator of the benchmark's inputs: the operands.
+"""The one generator of the benchmark's inputs: the operands and their
+blocking.
 
 It reads a configuration (sizes, dtype, mesh) and a traffic mix (fills,
-value distribution), both plain data files, and builds everything from
-``--seed``.  The operands are made on the device in one jitted call,
+value distribution, an optional tiling), both plain data files.  The
+operands are made on the device from ``--seed`` in one jitted call,
 straight into the engine's SUMMA shards, by an elementwise hash of each
 element's coordinates: no random-bit buffer, no host copy, so making them
 sets no memory peak of its own.
 
+A mix may name a logical tiling (``"blocks"``, ``block_sizes``).  It is
+fixed by the mix, never by ``--seed``: a deployment's blocking comes from
+its basis, and the padded extent it gives sets the kernel's tiles.  Which
+engine call a cell times is its configuration's entry
+(``chipbench/entries/``); each entry checks that the mix is one it can
+run.
+
 Both operands are dense.  A block-sparse mix needs an occupancy and a
 block structure that a public source gives, and the generator of that
-structure here, with A's mask passed through the harness, the reference
-and the work count (``chipbench/work.py``).
+structure here, with A's mask passed to an entry, the reference and the
+work count (``chipbench/work.py``).
 """
 from __future__ import annotations
 
@@ -21,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-__all__ = ["check_traffic", "seed_keys", "make_operands", "mesh_sharding"]
+__all__ = ["check_traffic", "block_sizes", "seed_keys", "make_operands", "mesh_sharding"]
 
 _M1 = np.uint32(0x85EBCA6B)
 _M2 = np.uint32(0xC2B2AE35)
@@ -35,6 +43,24 @@ def check_traffic(traffic: dict) -> None:
     for key, value in wanted.items():
         if traffic.get(key) != value:
             raise ValueError(f"traffic {traffic.get('name')!r}: {key} must be {value!r}")
+
+
+def block_sizes(traffic: dict, n: int) -> tuple[int, ...]:
+    """The mix's logical block sizes of a dimension of extent ``n``.
+
+    ``"blocks": "paper_4_1"`` is the paper's section 4.1 procedure (arXiv:
+    1504.05046): ``n // mean_block`` blocks of one row each, the other rows
+    added one at a time at random, here by a multinomial draw over weights
+    uniform in [0.9, 1.1] from ``tiling_seed``.  A copy of
+    ``repro.core.blocking.nonuniform_tiling``, kept here so that an edit to
+    the program cannot move the cell's structure."""
+    if traffic.get("blocks") != "paper_4_1":
+        raise ValueError(f"traffic {traffic.get('name')!r}: blocks must be 'paper_4_1', not {traffic.get('blocks')!r}")
+    count = n // int(traffic["mean_block"])
+    rng = np.random.default_rng(int(traffic["tiling_seed"]))
+    weights = rng.uniform(0.9, 1.1, size=count)
+    weights /= weights.sum()
+    return tuple(int(c) for c in rng.multinomial(n - count, weights) + 1)
 
 
 def seed_keys(seed: int) -> np.ndarray:

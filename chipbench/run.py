@@ -12,24 +12,24 @@ exits 2 and prints no result.
 
 Everything is found by name: the cell names a configuration
 (``configs[].file``) and a traffic mix (``chipbench/traffic/<mix>.json``);
-each per-layer metric is read by ``chipbench/metrics/<metric>.py``.  A new
-cell is new files and entries, with no edit here, where it keeps to what
-the loop and the generator make: ``DistributedMatmul`` of a configuration
-(N, block, dtypes, mesh, strategy), a closed loop with one client, normal
-values, A and B dense (``chipbench/generate.py``).  A block-sparse operand,
-another entry (``NonuniformMatmul``, the rank-sparse or ``contract_chain``
-paths), an open loop or several clients each need an edit to this file or
-to the generator.
+the configuration names its entry, the engine call the cell times
+(``chipbench/entries/<entry>.py``, ``"dense"`` where it names none); each
+per-layer metric is read by ``chipbench/metrics/<metric>.py``.  A new
+cell, a new entry among them, is new files and entries, with no edit
+here.  What the harness keeps is the loop: a closed loop with one client,
+normal values (``chipbench/generate.py``).  An open loop or several
+clients need an edit to this file or to the generator.
 
-The loop is closed, with one client: the caller issues
-``DistributedMatmul.__call__`` (``core/api.py``) on fixed operands, waits
-for its result, and issues the next, the way an iterative solver does.
-Set-up (imports, operands made on the device from ``--seed``, compiling or
-loading from the compile cache, two warm-up calls) runs before the window;
-``setup_s`` is process start to the first timed call.  The window runs for
-``--seconds`` and ends with the call in flight.  After it, the device's
-memory peak is read, the engine is dropped, and the last call's C is
-compared with the plain reference (``chipbench/reference.py``).
+The loop is closed, with one client: the caller issues the entry's call
+(``DistributedMatmul.__call__`` or ``NonuniformMatmul.__call__``,
+``core/api.py``) on fixed operands, waits for its result, and issues the
+next, the way an iterative solver does.  Set-up (imports, operands made on
+the device from ``--seed``, compiling or loading from the compile cache,
+two warm-up calls) runs before the window; ``setup_s`` is process start to
+the first timed call.  The window runs for ``--seconds`` and ends with the
+call in flight.  After it, the device's memory peak is read, the engine is
+dropped, and the last call's C is compared with the plain reference
+(``chipbench/reference.py``) by the entry.
 """
 from __future__ import annotations
 
@@ -86,13 +86,31 @@ def load_cell(name: str, root: str = ROOT):
     return bench, cell, config, traffic
 
 
-def metric_reader(name: str, root: str = ROOT):
-    """The ``read`` function of ``chipbench/metrics/<name>.py``."""
-    path = os.path.join(root, "chipbench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"chipbench.metrics.{name}", path)
+def _module(kind: str, name: str, root: str):
+    """``chipbench/<kind>/<name>.py``, loaded by its path."""
+    path = os.path.join(root, "chipbench", kind, name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"chipbench: no {kind} module {name!r} ({path})")
+    spec = importlib.util.spec_from_file_location(f"chipbench.{kind}.{name}", path)
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
-    return module.read
+    return module
+
+
+def metric_reader(name: str, root: str = ROOT):
+    """The ``read`` function of ``chipbench/metrics/<name>.py``."""
+    return _module("metrics", name, root).read
+
+
+def entry_module(name: str, root: str = ROOT):
+    """The module ``chipbench/entries/<name>.py``: ``check_traffic``,
+    ``engine`` and ``build`` of one engine call."""
+    return _module("entries", name, root)
+
+
+def entry_of(config: dict):
+    """The entry module a configuration names; ``dense`` where it names none."""
+    return entry_module(config.get("entry", "dense"))
 
 
 def require_devices(chips: int):
@@ -158,6 +176,8 @@ class RunContext:
     host_call_s: list
     peak: dict | None  # chipbench/peaks.json entry of the device kind
     trace: object = None  # xplane.Trace of the window, or None
+    # the program's counters, read after the window (Product.counters)
+    counters: dict = dataclasses.field(default_factory=dict)
     # the window's (start, end) on the host's clock in the trace; the
     # device's clock in the same trace may be off from it by a millisecond
     window: tuple | None = None
@@ -246,24 +266,15 @@ def run_cell(
     """
     _paths()
     import jax
-    import jax.numpy as jnp
-
-    from chipbench import generate, reference
-    from repro.core import DistributedMatmul
 
     watch = CompileWatch()
     log(f"{config['name']}: {config['guarantee']}")
     mesh = make_mesh(config, devices)
-    generate.check_traffic(traffic)
-    mm = DistributedMatmul(
-        mesh, strategy=config["strategy"], local_matmul=config["local_matmul"],
-        accum_dtype=jnp.dtype(config["accum_dtype"]),
-    )
-    a, b = generate.make_operands(config, seed, mesh)
-    jax.block_until_ready((a, b))
+    product = entry_of(config).build(config, traffic, seed, mesh)
+    jax.block_until_ready((product.a, product.b))
     for _ in range(WARMUP_CALLS):
-        jax.block_until_ready(mm(a, b))
-    stats0 = mm.cache_stats()
+        jax.block_until_ready(product.call())
+    stats0 = product.cache_stats()
     setup_peak = _peak_bytes(devices)
     log(f"device 0 memory stats after set-up: {json.dumps(devices[0].memory_stats())}")
     log(f"set-up: {time.perf_counter() - T_START:.3f} s, device memory peak {setup_peak / GIB:.3f} GiB")
@@ -287,7 +298,7 @@ def run_cell(
             c = None
             t_call = time.perf_counter()
             with annotate("chipbench.call"):
-                c = mm(a, b)
+                c = product.call()
             host_call_s.append(time.perf_counter() - t_call)
             with annotate("chipbench.wait"):
                 c.block_until_ready()
@@ -298,7 +309,7 @@ def run_cell(
     if tracing:
         jax.profiler.stop_trace()
     calls = len(host_call_s)
-    stats1 = mm.cache_stats()
+    stats1 = product.cache_stats()
     for key, after in (("plan", stats1["plan"]), ("executable", stats1["executable"])):
         before = stats0[key]
         if after.get("misses") != before.get("misses") or after.get("retraces", 0) != before.get("retraces", 0):
@@ -307,13 +318,14 @@ def run_cell(
         log(f"WINDOW: trace/compile events inside the window (s): {json.dumps(watch.events)}")
     log(f"window: {calls} calls in {window_s:.6f} s; cache stats {json.dumps(stats1)}")
     peak_bytes = _peak_bytes(devices)
+    counters = product.counters()
 
     # the program's state goes before the reference runs
-    del mm
-    values = reference.compare(a, b, c, int(config["block"]), mesh)
+    product.drop()
+    values = product.compare(c)
     checks = {k: {"value": v, "limit": float(config["limits"][k])} for k, v in values.items()}
     correct = all(ch["value"] < ch["limit"] for ch in checks.values())
-    del a, b, c
+    del product, c
 
     d0 = devices[0]
     device = {
@@ -332,7 +344,7 @@ def run_cell(
         run = RunContext(
             cell=cell, config=config, traffic=traffic, chips=len(devices), device_ids=[d.id for d in devices], calls=calls,
             window_s=window_s, host_call_s=host_call_s, peak=peak,
-            trace=trace, window=(win[0].start, win[0].end),
+            trace=trace, window=(win[0].start, win[0].end), counters=counters,
         )
         for m in per_layer or []:
             if "workloads" in m and cell["name"] not in m["workloads"]:

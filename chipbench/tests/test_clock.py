@@ -96,11 +96,16 @@ def test_readers_of_the_engine_read_nothing_without_its_spans(name, cell, monkey
 DELTA = 1.5e-3  # host = device + DELTA
 
 
-def _synthetic(products=3, chips=1, delta=DELTA, late_done=None):
+def _synthetic(products=3, chips=1, delta=DELTA, late_done=None, gather=False, stall=None):
     """A closed loop of ``products`` calls 1 s apart; on each chip a product
     runs 0.6 s from device time p (two ops), every host event placed by
     ``delta``.  ``late_done`` (a product) moves that product's done event
-    before its last op ends on the host's clock."""
+    before its last op ends on the host's clock.  ``gather`` opens each
+    call with a padding gather, enqueued before ``repro.execute`` and run
+    on the chip before the product, as a nonuniform call does.  ``stall``
+    (a product) leaves the chip idle for 0.45 s inside that product, longer
+    than the 0.4 s between products, as a host stall between two enqueues
+    of one call does."""
     host = [Event("chipbench.window", -0.01, products + 0.0)]
     for p in range(products):
         t = p + delta  # the product's first op, on the host's clock
@@ -115,7 +120,14 @@ def _synthetic(products=3, chips=1, delta=DELTA, late_done=None):
             Event("chipbench.wait", t - 5e-5, t + 0.6 + 3e-4),
             Event("tpu::System::Execute=>Done", done, done + 1e-5),
         ]
-    ops = [e for p in range(products) for e in (Event("%k.1", p, p + 0.5), Event("%c.1", p + 0.5, p + 0.6))]
+        if gather:
+            host.append(Event("DoEnqueueProgram", t - 5.8e-4, t - 5.6e-4))
+    ops = [
+        e for p in range(products)
+        for e in (Event("%k.1", p, p + (0.1 if p == stall else 0.5)), Event("%c.1", p + (0.55 if p == stall else 0.5), p + 0.6))
+    ]
+    if gather:
+        ops = sorted(ops + [Event("%g.1", p - 3.5e-4, p) for p in range(products)], key=lambda e: e.start)
     trace = xplane.Trace(
         [f"/device:TPU:{i}" for i in range(chips)], [list(ops) for _ in range(chips)],
         sorted(host, key=lambda e: e.start),
@@ -129,6 +141,23 @@ def test_synthetic_offset_is_recovered():
     assert o.hi == pytest.approx(DELTA + 2e-4)
     assert o.mid == pytest.approx(DELTA)
     assert (o.launch, o.done) == ("DoEnqueueProgram", "tpu::System::Execute=>Done")
+
+
+def test_synthetic_offset_with_a_gather_before_the_product():
+    """The gather's launch, inside ``repro.matmul`` and before
+    ``repro.execute``, bounds the offset from below."""
+    (o,) = clock.offsets(_synthetic(gather=True))
+    assert o.lo == pytest.approx(DELTA - 2.3e-4)
+    assert o.hi == pytest.approx(DELTA + 2e-4)
+    assert o.lo <= DELTA <= o.hi
+
+
+def test_synthetic_offset_with_a_stall_inside_a_call():
+    """Each call runs the same operations, so they are split by count, not
+    at the widest idle gap, which here lies inside a call."""
+    (o,) = clock.offsets(_synthetic(stall=1))
+    assert (o.lo, o.hi) == pytest.approx((DELTA - 2e-4, DELTA + 2e-4))
+    assert clock.products(_synthetic(stall=1).device_ops()[0], 3) == [(0, 0.6), (1, 1.6), (2, 2.6)]
 
 
 def test_synthetic_offset_without_runtime_events():

@@ -3,8 +3,10 @@
 No chip is attached: the TPU compiler compiles for a described one, so a
 program that does not fit the chip's memory, or a kernel Mosaic refuses,
 fails here before any chip time is spent.  Compiled: the engine's call
-as the window drives it, the operand generator, and the reference's
-comparison.  Nothing runs, so these tests say nothing about results or
+of the cell's entry as one program, the operand generator, and the
+reference's comparison; for the nonuniform entry also the eager steps
+the window drives one by one (the padded product, the compaction), with
+every buffer alive beside them.  Nothing runs, so these tests say nothing about results or
 speed.
 
 The topology is described inside a fixture, never at import: only one
@@ -68,18 +70,13 @@ def _total(compiled) -> int:
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_programs_fit_a_v5e(cell, topo, compiled_kernels):
-    from repro.core import DistributedMatmul
-
-    _, w, config, _ = run.load_cell(cell)
+    _, w, config, traffic = run.load_cell(cell)
     n, block = config["n"], config["block"]
     mesh = run.make_mesh(config, topo.devices[: w["chips"]])
     spec = jax.ShapeDtypeStruct(
         (n, n), jnp.dtype(config["dtype"]), sharding=generate.mesh_sharding(mesh)
     )
-    mm = DistributedMatmul(
-        mesh, strategy=config["strategy"], local_matmul=config["local_matmul"],
-        accum_dtype=jnp.dtype(config["accum_dtype"]),
-    )
+    mm = run.entry_of(config).engine(config, traffic, mesh)
     engine = jax.jit(lambda x, y: mm(x, y)).lower(spec, spec).compile()
     assert "tpu_custom_call" in engine.as_text()
     assert _total(engine) <= CHIP_BYTES, engine.memory_analysis()
@@ -96,3 +93,36 @@ def test_cell_programs_fit_a_v5e(cell, topo, compiled_kernels):
     m = engine.memory_analysis()
     beside = m.argument_size_in_bytes + m.output_size_in_bytes
     assert beside + cmp.memory_analysis().temp_size_in_bytes <= CHIP_BYTES
+
+
+def test_nonuniform_steps_fit_a_v5e(topo, compiled_kernels):
+    """The nonuniform call runs eagerly: per operand a row gather and its
+    select, a column gather and its select, then the padded product and
+    the compaction's two gathers, each a program of its own.  The host
+    enqueues the whole call before the chip has run it, so every buffer
+    the call makes can be alive at once (a v5e held 15.23 GiB at N =
+    18432, where this sum is 16.1 GiB).  At N = 14848, padded 22016, that
+    sum, with the padded product's temporaries, keeps 2.25 GiB of the
+    chip free."""
+    _, w, config, traffic = run.load_cell("commodity.nonuniform")
+    n = config["n"]
+    mesh = run.make_mesh(config, topo.devices[: w["chips"]])
+    nm = run.entry_of(config).engine(config, traffic, mesh)
+    padded = nm.row_b.padded_extent
+    assert padded == nm.inner_b.padded_extent == nm.col_b.padded_extent == 22016
+    dtype = jnp.dtype(config["dtype"])
+    sharding = generate.mesh_sharding(mesh)
+    spec_p = jax.ShapeDtypeStruct((padded, padded), dtype, sharding=sharding)
+    product = jax.jit(lambda x, y: nm.mm(x, y)).lower(spec_p, spec_p).compile()
+    assert "tpu_custom_call" in product.as_text()
+    compact = jax.jit(nm._compact).lower(spec_p).compile()
+    m_p, m_c = product.memory_analysis(), compact.memory_analysis()
+    item = dtype.itemsize
+    assert m_p.output_size_in_bytes == padded * padded * item
+    assert m_c.output_size_in_bytes == n * n * item
+    per_operand = 2 * padded * n * item + 2 * padded * padded * item
+    whole_call = (
+        2 * n * n * item + 2 * per_operand + m_p.output_size_in_bytes + m_p.temp_size_in_bytes
+        + padded * n * item + m_c.output_size_in_bytes
+    )
+    assert whole_call <= CHIP_BYTES - 2.25 * 2**30, whole_call / 2**30

@@ -13,15 +13,21 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import generate, reference, run
+from chipbench import generate, run
 
 TINY = {"n": 512, "block": 128}
-CELLS = ["commodity.dense", "bgq.dense.2x2"]
+#: the nonuniform cell at a tiny size: ten logical blocks of 112-151 rows,
+#: 128-wide tiles, four blocks over one tile
+TINY_NONUNIFORM = ({"n": 1280, "block": 128}, {"mean_block": 128})
+CELLS = ["commodity.dense", "bgq.dense.2x2", "commodity.nonuniform"]
 
 
 def _tiny(cell):
     bench, w, config, traffic = run.load_cell(cell)
-    config = dict(config, **TINY)
+    if config.get("entry") == "nonuniform":
+        config, traffic = dict(config, **TINY_NONUNIFORM[0]), dict(traffic, **TINY_NONUNIFORM[1])
+    else:
+        config = dict(config, **TINY)
     p_row, p_col = config["mesh"]
     return bench, w, config, traffic, jax.devices()[: p_row * p_col]
 
@@ -79,12 +85,10 @@ def test_every_hash_gives_a_finite_normal():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_int8_control_fails_a_limit(cell):
-    _, _, config, _, devices = _tiny(cell)
-    mesh = run.make_mesh(config, devices)
-    block = config["block"]
-    a, b = generate.make_operands(config, 11, mesh)
-    c = reference.int8_control(a, b, block, config["out_dtype"], mesh)
-    values = reference.compare(a, b, c, block, mesh)
+    _, _, config, traffic, devices = _tiny(cell)
+    product = run.entry_of(config).build(config, traffic, 11, run.make_mesh(config, devices))
+    product.drop()
+    values = product.compare(product.control())
     limits = config["limits"]
     assert any(values[k] >= limits[k] for k in limits), values
 
@@ -113,12 +117,38 @@ def _exchange_fault(monkeypatch, fault):
     monkeypatch.setattr(summa, "_bcast_panel", lambda slab, owner, axis: slab)
 
 
+def _compaction_fault(monkeypatch, fault):
+    """C compacted with the rows of logical blocks 0 and 1 swapped, or
+    with the rows of the first remainder tile (a block's second physical
+    tile) left zero."""
+    from repro.core.api import NonuniformMatmul
+
+    orig = NonuniformMatmul._compact
+
+    def broken(self, c):
+        out = orig(self, c)
+        tiles = self.row_b
+        if fault == "misplaced":
+            s0, s1 = self.row_tiling.sizes[:2]
+            order = np.r_[s0:s0 + s1, 0:s0, s0 + s1:out.shape[0]]
+            return out[jnp.asarray(order)]
+        t = next(t for t in range(1, tiles.num_tiles) if tiles.block_id[t] == tiles.block_id[t - 1])
+        rows = tiles.gather_indices()[t * tiles.tile: t * tiles.tile + tiles.valid[t]]
+        return out.at[jnp.asarray(rows)].set(0)
+
+    monkeypatch.setattr(NonuniformMatmul, "_compact", broken)
+
+
 FAULTS = [
     ("commodity.dense", "altered", _dense_fault),
     ("commodity.dense", "half", _dense_fault),
     ("bgq.dense.2x2", "altered", _dense_fault),
     ("bgq.dense.2x2", "half", _dense_fault),
     ("bgq.dense.2x2", "no_exchange", _exchange_fault),
+    ("commodity.nonuniform", "altered", _dense_fault),
+    ("commodity.nonuniform", "half", _dense_fault),
+    ("commodity.nonuniform", "misplaced", _compaction_fault),
+    ("commodity.nonuniform", "dropped", _compaction_fault),
 ]
 
 

@@ -1,5 +1,5 @@
 """``BENCHMARK.json`` keeps to the benchmark's contract, and every cell's
-configuration, traffic mix and per-layer metric resolves by name."""
+configuration, entry, traffic mix and per-layer metric resolves by name."""
 import json
 import math
 import os
@@ -84,6 +84,7 @@ def test_cell_resolves_by_name(cell):
     assert config["mesh"][0] * config["mesh"][1] == w["chips"]
     assert set(config["limits"]) == {"worst_tile_rel_err", "worst_element_err"}
     generate.check_traffic(traffic)
+    run.entry_of(config).check_traffic(traffic)
     reported_e2e = [m for m in bench["end_to_end"] if cell in m.get("workloads", CELLS)]
     assert "setup_s" in {m["name"] for m in reported_e2e} and len(reported_e2e) >= 2
     layer = [m for m in bench["per_layer"] if cell in m.get("workloads", CELLS)]
@@ -97,6 +98,61 @@ def test_generator_refuses_a_mix_it_cannot_make(key, value):
     _, _, _, traffic = run.load_cell("commodity.dense")
     with pytest.raises(ValueError, match=key):
         generate.check_traffic(dict(traffic, **{key: value}))
+
+
+def test_entry_refuses_a_mix_it_cannot_run():
+    _, _, _, uniform = run.load_cell("commodity.dense")
+    _, _, _, blocked = run.load_cell("commodity.nonuniform")
+    with pytest.raises(ValueError, match="tiling"):
+        run.entry_module("dense").check_traffic(blocked)
+    with pytest.raises(ValueError, match="tiling"):
+        run.entry_module("nonuniform").check_traffic(uniform)
+    with pytest.raises(ValueError, match="loop"):
+        run.entry_module("nonuniform").check_traffic(dict(blocked, loop="open"))
+    with pytest.raises(ValueError, match="blocks"):
+        generate.block_sizes(dict(blocked, blocks="uniform_random"), 1024)
+
+
+@pytest.mark.parametrize("name", sorted({c.get("entry", "dense") for c in (
+    run._json(os.path.join(ROOT, e["file"])) for e in BENCH["configs"])}))
+def test_every_entry_resolves_by_name(name):
+    module = run.entry_module(name)
+    assert all(callable(getattr(module, f)) for f in ("check_traffic", "engine", "build"))
+
+
+def test_a_new_entry_is_a_new_file(tmp_path):
+    """An entry is found by name, as a metric reader is: a module dropped
+    into ``chipbench/entries/`` needs no edit to the harness."""
+    (tmp_path / "chipbench" / "entries").mkdir(parents=True)
+    (tmp_path / "chipbench" / "entries" / "probe.py").write_text(
+        "def check_traffic(traffic):\n    return None\n\n"
+        "def engine(config, traffic, mesh):\n    return 'probe'\n\n"
+        "def build(config, traffic, seed, mesh):\n    return (config, seed)\n"
+    )
+    module = run.entry_module("probe", root=str(tmp_path))
+    assert module.build({"n": 8}, {}, 3, None) == ({"n": 8}, 3)
+    assert run.entry_of({}).__name__ == "chipbench.entries.dense"
+    with pytest.raises(ValueError, match="no entries module"):
+        run.entry_module("absent", root=str(tmp_path))
+
+
+def test_the_nonuniform_tiling_is_the_papers():
+    """The mix's tiling is the program's section 4.1 tiling at the mix's
+    parameters, fixed by the mix: 58 blocks of 210-303 rows, of which 28
+    take two 256-wide tiles, padded to 22016."""
+    from repro.core import blocking
+
+    _, _, config, traffic = run.load_cell("commodity.nonuniform")
+    n, mean = config["n"], traffic["mean_block"]
+    sizes = generate.block_sizes(traffic, n)
+    assert sizes == blocking.nonuniform_tiling(n, n // mean, traffic["tiling_seed"]).sizes
+    assert sizes == generate.block_sizes(dict(traffic), n)
+    assert (len(sizes), sum(sizes), min(sizes), max(sizes)) == (58, 14848, 210, 303)
+    assert sum(s > config["block"] for s in sizes) == 28
+    assert blocking.bucketize(blocking.Tiling(sizes), config["block"]).padded_extent == 22016
+    for n, mean, seed in ((1280, 128, 0), (4096, 256, 7)):
+        t = dict(traffic, mean_block=mean, tiling_seed=seed)
+        assert generate.block_sizes(t, n) == blocking.nonuniform_tiling(n, n // mean, seed).sizes
 
 
 def test_every_config_is_used_and_files_are_distinct():

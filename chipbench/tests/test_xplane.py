@@ -9,12 +9,13 @@ nine products, each with four panel broadcasts (``psum``, HLO
 numbers are worked out here from the raw profile, independently of
 ``xplane``.
 """
+import dataclasses
 import os
 
 import pytest
 from jax.profiler import ProfileData
 
-from chipbench import kernel_roofline, run, work, xplane
+from chipbench import generate, kernel_roofline, run, work, xplane
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 DENSE = os.path.join(DATA, "commodity_dense_1x1.xplane.pb")
@@ -182,3 +183,45 @@ def test_mesh_kernel_roofline(mesh_run):
     value, _ = run.metric_reader("tiled_matmul_roofline")(mesh_run)
     assert value == pytest.approx(100.0 * 4 * 9 * least / kernel, rel=1e-9)
     assert 0 < value <= 100
+
+
+@pytest.mark.parametrize("path,cell,calls", [(DENSE, "commodity.dense", 5), (MESH, "bgq.dense.2x2", 9)])
+def test_layout_ms_against_raw_profile(path, cell, calls):
+    """Every op that is neither the kernel nor a panel broadcast, summed
+    from the raw profile: the bf16 cast on one chip; on 2x2 the zeroing of
+    panels and the add and cast besides."""
+    chips = 4 if cell == "bgq.dense.2x2" else 1
+    layout = [
+        sum(d for text, _, d in _raw_device_ops(path, dev)
+            if not text.startswith("tiled_matmul") and " all-reduce(" not in text)
+        for dev in range(chips)
+    ]
+    assert all(layout)
+    want = 1e3 * sum(layout) / chips / calls
+    assert run.metric_reader("layout_ms")(_context(path, cell)) == pytest.approx(want, rel=1e-9)
+
+
+def test_useful_flops_share_from_the_programs_counter():
+    """``100 prod(1 - padding_waste)``, the logical N^3 over the padded
+    extents, with the counter as the nonuniform entry reads it from
+    ``NonuniformMatmul``; checked against ``bucketize`` at a small tiling.
+    Nothing to read without the counter."""
+    import jax
+    from repro.core import blocking
+
+    _, cell, config, traffic = run.load_cell("commodity.nonuniform")
+    config = dict(config, n=1280, block=128)
+    traffic = dict(traffic, mean_block=128)
+    entry = run.entry_of(config)
+    mesh = run.make_mesh(config, jax.devices()[:1])
+    counters = entry.Product(config, entry.engine(config, traffic, mesh), None, None, mesh).counters()
+    tiles = blocking.bucketize(blocking.Tiling(generate.block_sizes(traffic, 1280)), 128)
+    assert tiles.padded_extent == 1664
+    assert counters == {"padding_waste": {d: tiles.padding_waste for d in ("rows", "inner", "cols")}}
+    ctx = run.RunContext(
+        cell=cell, config=config, traffic=traffic, chips=1, device_ids=[0], calls=1,
+        window_s=1.0, host_call_s=[], peak=PEAK, counters=counters,
+    )
+    read = run.metric_reader("useful_flops_share")
+    assert read(ctx) == pytest.approx(100.0 * (1280 / 1664) ** 3, rel=1e-12)
+    assert read(dataclasses.replace(ctx, counters={})) is None
